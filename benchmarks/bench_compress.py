@@ -161,7 +161,7 @@ def run_cell(mechanism: str, bits: int, size: int) -> dict:
     dec_xla = lambda w, sm: dc.decode_leaf_sum(
         w, comp_f, n, n, step, offset, sm, geom, (size,))
     dec_pal = lambda w, sm: ops.fused_unpack_decode(
-        w, sm + float(n) * geom.bias, step / n, offset, geom.bits,
+        w, sm, n * geom.bias, step / n, offset, geom.bits,
         (size,), impl="pallas")
     dec_unf = jax.jit(lambda m, sm: dc.decode_leaf_sum(
         m, comp_u, n, n, step, offset, sm, geom, (size,)))

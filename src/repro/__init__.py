@@ -1,9 +1,2 @@
 """Reproduction of "Compression with Exact Error Distribution for
-Federated Learning" as a sharded jax training/serving system.
-
-Importing the package installs the jax version-compat shims (see
-``repro.compat``) so every module can use the modern API spellings.
-"""
-from repro import compat as _compat
-
-_compat.install()
+Federated Learning" as a sharded jax training/serving system."""

@@ -37,6 +37,8 @@ from repro.core.decompose import (
 
 __all__ = ["AggregateGaussianMechanism", "AggGaussShared"]
 
+DECOMPOSE_BATCH = 1 << 20  # coordinates per vmapped per-coordinate draw
+
 
 class AggGaussShared(NamedTuple):
     """Global shared randomness T = (A, B) (scalar or per-coordinate)."""
@@ -110,8 +112,13 @@ class AggregateGaussianMechanism:
                 A, B = jax.lax.map(
                     lambda k: decompose_gaussian(tables, k), keys)
             else:
-                A, B = jax.vmap(
-                    lambda k: decompose_gaussian(tables, k))(keys)
+                # vmapped in batches: one vmap over a whole embedding
+                # leaf holds the rejection loop's per-lane keys and
+                # splits for every coordinate at once (GBs of HBM);
+                # lanes are independent, so batching is bit-identical
+                A, B = jax.lax.map(
+                    lambda k: decompose_gaussian(tables, k), keys,
+                    batch_size=DECOMPOSE_BATCH)
             A, B = A.reshape(shape), B.reshape(shape)
         else:
             A, B = decompose_gaussian(tables, key)

@@ -255,9 +255,9 @@ def decode_leaf_sum(m_sum, comp: CompressionConfig, n, r_msgs,
                 jnp.all(fields <= jnp.uint32(r_msgs * 2 * geom.m_max)),
                 "decode: packed field sum exceeds r * 2 * m_max "
                 "(overflowed or tampered lane)")
-        s_eff = s_sum + jnp.float32(r_msgs) * geom.bias
+        bias_sum = jnp.asarray(r_msgs).astype(jnp.int32) * geom.bias
         y = ops.fused_unpack_decode(
-            m_sum, s_eff, step_dec, offset, geom.bits, shape
+            m_sum, s_sum, bias_sum, step_dec, offset, geom.bits, shape
         )
         if debug.active():
             debug.check(jnp.all(jnp.isfinite(y)),

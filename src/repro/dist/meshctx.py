@@ -7,11 +7,12 @@ One mesh per process, three axes:
   data  — within-pod data parallelism + ZeRO/FSDP param sharding
   model — tensor parallelism
 
-``default_mesh()`` builds a (pod, data, model) mesh over whatever
-devices exist.  On a CPU host it first forces
-``--xla_force_host_platform_device_count=8`` (when the backend is not
-yet initialized) so pod-axis tests exercise real multi-device paths
-instead of silently collapsing to one device.
+``make_mesh`` is the one mesh constructor: every axis is
+``AxisType.Auto``, so GSPMD propagates shardings and the activation
+constraints of ``nn.shard_activation`` may name any axis (jax's own
+``make_mesh`` defaults to ``Explicit`` axes, which reject them).
+``default_mesh()`` shapes a (pod, data, model) mesh over whatever
+devices exist.
 
 ``manual_axes({...})`` records which mesh axes are currently manual
 (inside a ``shard_map``); ``nn.shard_activation`` and
@@ -22,35 +23,25 @@ already-manual axis.
 from __future__ import annotations
 
 import contextlib
-import os
-from typing import FrozenSet, Iterable, Optional, Tuple
+from typing import FrozenSet, Iterable, Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-_FORCE_FLAG = "--xla_force_host_platform_device_count"
-DEFAULT_HOST_DEVICE_COUNT = 8
+AXES = ("pod", "data", "model")
 
 _mesh: Optional[Mesh] = None
 _manual: FrozenSet[str] = frozenset()
 
 
-def _backend_initialized() -> bool:
-    try:
-        from jax._src import xla_bridge
-
-        return bool(xla_bridge._backends)
-    except Exception:  # noqa: BLE001 — unknown jax internals: assume live
-        return True
-
-
-def force_host_device_count(n: int = DEFAULT_HOST_DEVICE_COUNT) -> None:
-    """Ask XLA for ``n`` host (CPU) devices.  No-op if the flag is
-    already present or the backend has initialized (too late to change)."""
-    flags = os.environ.get("XLA_FLAGS", "")
-    if _FORCE_FLAG in flags or _backend_initialized():
-        return
-    os.environ["XLA_FLAGS"] = f"{flags} {_FORCE_FLAG}={n}".strip()
+def make_mesh(shape: Sequence[int], axes: Sequence[str] = AXES, *,
+              devices=None) -> Mesh:
+    """A mesh of ``shape`` over ``axes`` with every axis Auto.
+    ``devices`` defaults to ``jax.devices()``; pass a described
+    topology's devices to compile for a chip that is not attached."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def default_mesh() -> Mesh:
@@ -60,13 +51,12 @@ def default_mesh() -> Mesh:
     device count allows: 8 devices -> (2, 2, 2), 4 -> (2, 1, 2),
     2 -> (2, 1, 1), 1 -> (1, 1, 1).
     """
-    force_host_device_count()
     n = len(jax.devices())
     pod = 2 if n % 2 == 0 and n > 1 else 1
     rem = n // pod
     model = 2 if rem % 2 == 0 and rem > 1 else 1
     data = rem // model
-    return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
+    return make_mesh((pod, data, model))
 
 
 def set_mesh(mesh: Mesh) -> None:

@@ -19,10 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_tpu_compiler_params
-
-_CompilerParams = pallas_tpu_compiler_params()
-
 NEG_INF = -1e30
 
 
@@ -103,7 +99,7 @@ def flash_attention_tpu(q, k, v, *, causal: bool = True, bq: int = 128,
             pltpu.VMEM((bq, 128), jnp.float32),  # running sum
             pltpu.VMEM((bq, D), jnp.float32),  # output accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -19,10 +19,15 @@ Encode, one pass per (rows x 128) tile:
     m      = clamp(floor(x / step + s + 1/2), -m_max, m_max)
     word_c = sum_j (m[j, c] + m_max) << (bits * j)     G = 32//bits
 
-Decode (word_sum = psum of packed words, s_eff = dither_sum + r*m_max):
+Decode (word_sum = psum of packed words of r messages, bias_sum =
+r * m_max, s_sum = dither sum):
 
     u_j = (word_sum >> (bits * j)) & mask              (unsigned)
-    y   = (u - s_eff) * step_dec [+ offset]
+    y   = (float(u - bias_sum) - s_sum) * step_dec [+ offset]
+
+The bias comes off in int32, before the float conversion: a biased
+field sum near 2^24 has an f32 spacing of 1, so folding the dither into
+one ``s_sum + bias_sum`` float would round the dither to an integer.
 
 Layout matches dither_pack.py: (R, G, 128) tiles in VMEM, packing
 reduces over the G axis; shapes padded to row multiples by ops.py.
@@ -34,6 +39,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BLOCK_R = 256  # rows (of 128-lane vectors) per tile
 LANES = 128
@@ -49,16 +55,17 @@ def _quantize_pack(x, s, step, bits: int, m_max: int):
     return word
 
 
-def _unpack_affine(word, s_eff, step, offset, bits: int):
+def _unpack_affine(word, bias_sum, s_sum, step, offset, bits: int):
     g = max(32 // bits, 1)
     mask = (1 << bits) - 1
     outs = []
     for j in range(g):
         # arithmetic shift + mask extracts exact bits [b*j, b*(j+1)) even
         # when the top field occupies bit 31 of the summed word
-        outs.append(((word >> (bits * j)) & mask).astype(jnp.float32))
-    u = jnp.stack(outs, axis=1)  # (R, G, 128)
-    y = (u - s_eff) * step
+        u = (word >> (bits * j)) & mask
+        outs.append((u - bias_sum).astype(jnp.float32))
+    m = jnp.stack(outs, axis=1)  # (R, G, 128) signed message sums
+    y = (m - s_sum) * step
     return y if offset is None else y + offset
 
 
@@ -74,8 +81,8 @@ def _encode_kernel(*refs, step: float | None, bits: int, m_max: int):
 
 def _decode_kernel(*refs, step: float | None, has_offset: bool, bits: int):
     refs = list(refs)
-    w_ref, se_ref = refs[0], refs[1]
-    pos = 2
+    w_ref, b_ref, s_ref = refs[0], refs[1], refs[2]
+    pos = 3
     if step is None:
         st = refs[pos][...]
         pos += 1
@@ -83,7 +90,8 @@ def _decode_kernel(*refs, step: float | None, has_offset: bool, bits: int):
         st = step
     off = refs[pos][...] if has_offset else None
     o_ref = refs[-1]
-    o_ref[...] = _unpack_affine(w_ref[...], se_ref[...], st, off, bits)
+    o_ref[...] = _unpack_affine(w_ref[...], b_ref[0, 0], s_ref[...], st, off,
+                                bits)
 
 
 def fused_encode(x, s, step, bits: int, m_max: int, *,
@@ -112,11 +120,12 @@ def fused_encode(x, s, step, bits: int, m_max: int, *,
     )(*args)
 
 
-def fused_decode(word, s_eff, step, offset, bits: int, *,
+def fused_decode(word, bias_sum, s_sum, step, offset, bits: int, *,
                  interpret: bool = False):
-    """Summed packed words (R, 128) + effective dither s_eff = dither_sum
-    + r * m_max (R, G, 128) -> f32 (R, G, 128).  ``step`` is the DECODE
-    step (mechanism step / n); ``offset`` is the additive shared offset
+    """Summed packed words (R, 128), the packing bias of the summed
+    messages ``bias_sum`` = r * m_max ((1, 1) int32) and the dither sum
+    s_sum (R, G, 128) -> f32 (R, G, 128).  ``step`` is the DECODE step
+    (mechanism step / n); ``offset`` is the additive shared offset
     (B * sigma) or None."""
     R, L = word.shape
     G = max(32 // bits, 1)
@@ -124,8 +133,9 @@ def fused_decode(word, s_eff, step, offset, bits: int, *,
     grid = (pl.cdiv(R, bm),)
     spec3 = pl.BlockSpec((bm, G, LANES), lambda i: (i, 0, 0))
     scalar = isinstance(step, (int, float))
-    in_specs = [pl.BlockSpec((bm, LANES), lambda i: (i, 0)), spec3]
-    args = [word, s_eff]
+    in_specs = [pl.BlockSpec((bm, LANES), lambda i: (i, 0)),
+                pl.BlockSpec(memory_space=pltpu.SMEM), spec3]
+    args = [word, bias_sum, s_sum]
     if not scalar:
         in_specs.append(spec3)
         args.append(step)

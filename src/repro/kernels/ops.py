@@ -118,51 +118,55 @@ def fused_pack_encode(x, s, step, bits: int, m_max: int,
 @functools.partial(
     jax.jit, static_argnames=("step", "bits", "shape", "impl", "interpret")
 )
-def _fused_decode_scalar(word, s_eff, step, offset, bits, shape, impl,
-                         interpret):
+def _fused_decode_scalar(word, s_sum, bias_sum, step, offset, bits, shape,
+                         impl, interpret):
     g = max(32 // bits, 1)
-    se = _pad_rows(s_eff, g)
+    ss = _pad_rows(s_sum, g)
+    bs = jnp.asarray(bias_sum, jnp.int32).reshape(1, 1)
     off = None if offset is None else _pad_rows(
-        jnp.broadcast_to(offset, s_eff.shape), g)
+        jnp.broadcast_to(offset, s_sum.shape), g)
     if impl == "xla":
-        y = ref.fused_decode_ref(word, se, step, off, bits)
+        y = ref.fused_decode_ref(word, bs, ss, step, off, bits)
     else:
-        y = fg.fused_decode(word, se, step, off, bits, interpret=interpret)
+        y = fg.fused_decode(word, bs, ss, step, off, bits,
+                            interpret=interpret)
     return y.reshape(-1)[: math.prod(shape)].reshape(shape)
 
 
 @functools.partial(
     jax.jit, static_argnames=("bits", "shape", "impl", "interpret")
 )
-def _fused_decode_percoord(word, s_eff, step, offset, bits, shape, impl,
-                           interpret):
+def _fused_decode_percoord(word, s_sum, bias_sum, step, offset, bits, shape,
+                           impl, interpret):
     g = max(32 // bits, 1)
-    se = _pad_rows(s_eff, g)
-    tr = _pad_rows(jnp.broadcast_to(step, s_eff.shape), g, value=1.0)
+    ss = _pad_rows(s_sum, g)
+    bs = jnp.asarray(bias_sum, jnp.int32).reshape(1, 1)
+    tr = _pad_rows(jnp.broadcast_to(step, s_sum.shape), g, value=1.0)
     off = None if offset is None else _pad_rows(
-        jnp.broadcast_to(offset, s_eff.shape), g)
+        jnp.broadcast_to(offset, s_sum.shape), g)
     if impl == "xla":
-        y = ref.fused_decode_ref(word, se, tr, off, bits)
+        y = ref.fused_decode_ref(word, bs, ss, tr, off, bits)
     else:
-        y = fg.fused_decode(word, se, tr, off, bits, interpret=interpret)
+        y = fg.fused_decode(word, bs, ss, tr, off, bits, interpret=interpret)
     return y.reshape(-1)[: math.prod(shape)].reshape(shape)
 
 
-def fused_unpack_decode(word, s_eff, step_dec, offset, bits: int, shape,
-                        impl: str | None = None,
+def fused_unpack_decode(word, s_sum, bias_sum, step_dec, offset, bits: int,
+                        shape, impl: str | None = None,
                         interpret: bool | None = None):
     """Fused homomorphic decode of SUMMED packed words back to ``shape``:
-    unpack unsigned fields, subtract ``s_eff`` (= dither_sum + r * m_max
-    for r summed messages), rescale by ``step_dec`` (mechanism step / n;
+    unpack unsigned fields, subtract the packing bias ``bias_sum``
+    (= r * m_max for r summed messages; int, may be traced) and the
+    dither sum ``s_sum``, rescale by ``step_dec`` (mechanism step / n;
     scalar or array) and add ``offset`` (B * sigma, or None)."""
     interpret = _on_cpu() if interpret is None else interpret
     impl = _impl_default(impl)
     shape = tuple(shape)
     if isinstance(step_dec, (int, float)):
-        return _fused_decode_scalar(word, s_eff, float(step_dec), offset,
-                                    bits, shape, impl, interpret)
-    return _fused_decode_percoord(word, s_eff, step_dec, offset, bits,
-                                  shape, impl, interpret)
+        return _fused_decode_scalar(word, s_sum, bias_sum, float(step_dec),
+                                    offset, bits, shape, impl, interpret)
+    return _fused_decode_percoord(word, s_sum, bias_sum, step_dec, offset,
+                                  bits, shape, impl, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("sigma", "interpret"))

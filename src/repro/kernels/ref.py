@@ -71,11 +71,12 @@ def unpack_biased_ref(word, bits: int):
     )
 
 
-def fused_decode_ref(word, s_eff, step, offset, bits: int):
-    """Oracle for fused_agg._decode_kernel: unpack + subtract the
-    effective dither (dither_sum + r * m_max) + rescale [+ offset]."""
-    u = unpack_biased_ref(word, bits).astype(jnp.float32)
-    y = (u - s_eff) * step
+def fused_decode_ref(word, bias_sum, s_sum, step, offset, bits: int):
+    """Oracle for fused_agg._decode_kernel: unpack, subtract the packing
+    bias (r * m_max) in int32 and the dither sum in f32, rescale
+    [+ offset]."""
+    m = (unpack_biased_ref(word, bits) - bias_sum).astype(jnp.float32)
+    y = (m - s_sum) * step
     return y if offset is None else y + offset
 
 

@@ -8,20 +8,22 @@ device state.  Production target: TPU v5e, 256 chips/pod (16 x 16),
           aggregation runs over this axis; see repro.dist.compress)
   data  — within-pod data parallelism + ZeRO/FSDP param sharding
   model — tensor parallelism
+
+Both build through ``repro.dist.meshctx.make_mesh`` (all axes Auto).
 """
 from __future__ import annotations
 
-import jax
+from repro.dist.meshctx import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    if multi_pod:
+        return make_mesh((2, 16, 16), ("pod", "data", "model"))
+    return make_mesh((16, 16), ("data", "model"))
 
 
 def make_host_mesh(data: int = 1, model: int = 1, pod: int = 0):
     """Small mesh over however many (host) devices exist — tests/examples."""
     if pod:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return make_mesh((pod, data, model), ("pod", "data", "model"))
+    return make_mesh((data, model), ("data", "model"))

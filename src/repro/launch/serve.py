@@ -11,9 +11,7 @@ CPU-container usage (reduced config):
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-32b --smoke \
       --requests 8 --slots 4 --prompt-len 16 --gen 8
 
-On a TPU mesh the same entry point serves the full config with the
-decode-cell shardings from the dry-run (weights resident bf16 for
-<=14B archs per EXPERIMENTS.md Perf H1).
+Without --smoke the same entry point serves the full config.
 """
 from __future__ import annotations
 
@@ -24,7 +22,7 @@ from collections import deque
 import jax
 import numpy as np
 
-from repro import configs
+from repro import compile_cache, configs
 from repro.data import synthetic
 from repro.dist import meshctx
 from repro.models import nn, registry
@@ -82,7 +80,7 @@ def drive(engine: ServeEngine, params, requests, *, log=lambda *_: None):
     }
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=configs.ARCHS)
     ap.add_argument("--smoke", action="store_true")
@@ -97,31 +95,26 @@ def main():
                     help="run the lockstep oracle loop instead")
     ap.add_argument("--batch", type=int, default=2,
                     help="(--naive only) lockstep batch size")
-    args = ap.parse_args()
+    return ap
 
+
+def _setup(args):
     cfg = (configs.get_smoke_config(args.arch) if args.smoke
            else configs.get_config(args.arch))
     if args.smoke:
         cfg = cfg.scaled(compute_dtype="float32")
     mesh = make_host_mesh(data=len(jax.devices()), model=1)
     meshctx.set_mesh(mesh)
-
     params = nn.init_params(registry.param_specs(cfg), jax.random.PRNGKey(0))
+    return cfg, params
+
+
+def run_engine(args, log=print):
+    """The engine path of ``main``: ``args.requests`` random prompts of
+    ``args.prompt_len`` tokens through ``args.slots`` slots.  Returns
+    (cfg, outputs {rid: [token ids]}, stats)."""
+    cfg, params = _setup(args)
     P = args.prompt_len
-
-    if args.naive:
-        B = args.batch
-        prompts = synthetic.with_frontend_stubs(
-            {"tokens": jax.random.randint(
-                jax.random.PRNGKey(1), (B, P), 0, cfg.vocab)}, cfg)
-        t0 = time.perf_counter()
-        toks = naive_generate(cfg, params, prompts, args.gen)
-        dt = time.perf_counter() - t0
-        print(f"[serve] naive {B}x{args.gen} tokens in {dt:.2f}s "
-              f"({B * args.gen / dt:.1f} tok/s)")
-        print("[serve] sample token ids:", toks[0].tolist())
-        return
-
     engine = ServeEngine(cfg, max_slots=args.slots, max_prefill_len=P,
                          max_gen_len=args.gen, eos_id=args.eos)
     rng = np.random.default_rng(1)
@@ -129,13 +122,33 @@ def main():
         (r, rng.integers(0, cfg.vocab, size=(P,), dtype=np.int32), args.gen)
         for r in range(args.requests)
     ]
-    outputs, stats = drive(engine, params, requests, log=print)
-    print(f"[serve] {args.requests} requests x {args.gen} tokens on "
-          f"{args.slots} slots: {stats['tokens_out']} tokens, "
-          f"{stats['steps']} steps in {stats['wall_s']:.2f}s "
-          f"({stats['tokens_per_s']:.1f} tok/s, "
-          f"mean occupancy {stats['mean_occupancy']:.0%})")
-    print("[serve] sample token ids:", outputs[0])
+    outputs, stats = drive(engine, params, requests, log=log)
+    log(f"[serve] {args.requests} requests x {args.gen} tokens on "
+        f"{args.slots} slots: {stats['tokens_out']} tokens, "
+        f"{stats['steps']} steps in {stats['wall_s']:.2f}s "
+        f"({stats['tokens_per_s']:.1f} tok/s, "
+        f"mean occupancy {stats['mean_occupancy']:.0%})")
+    log(f"[serve] sample token ids: {outputs[0]}")
+    return cfg, outputs, stats
+
+
+def main():
+    args = build_parser().parse_args()
+    compile_cache.use_persistent_cache()
+    if not args.naive:
+        run_engine(args)
+        return
+    cfg, params = _setup(args)
+    B, P = args.batch, args.prompt_len
+    prompts = synthetic.with_frontend_stubs(
+        {"tokens": jax.random.randint(
+            jax.random.PRNGKey(1), (B, P), 0, cfg.vocab)}, cfg)
+    t0 = time.perf_counter()
+    toks = naive_generate(cfg, params, prompts, args.gen)
+    dt = time.perf_counter() - t0
+    print(f"[serve] naive {B}x{args.gen} tokens in {dt:.2f}s "
+          f"({B * args.gen / dt:.1f} tok/s)")
+    print("[serve] sample token ids:", toks[0].tolist())
 
 
 if __name__ == "__main__":

@@ -23,11 +23,12 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import Any, Dict, List, NamedTuple
 
 import jax
 import jax.numpy as jnp
 
-from repro import configs
+from repro import compile_cache, configs
 from repro.checkpoint import checkpoint
 from repro.data import synthetic
 from repro.dist import meshctx
@@ -76,7 +77,6 @@ def run_async(args) -> None:
         round_timeout_s=args.round_timeout, transport=args.transport,
         straggler_fraction=args.straggler_fraction,
         straggler_delay_s=args.straggler_delay,
-        compilation_cache_dir=args.compilation_cache,
         heartbeat_timeout_s=args.heartbeat_timeout,
         chaos=plan,
         checkpoint_dir=args.checkpoint_dir,
@@ -122,7 +122,7 @@ def run_async(args) -> None:
     print("[train] done")
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=configs.ARCHS)
     ap.add_argument("--smoke", action="store_true",
@@ -162,12 +162,10 @@ def main():
     ap.add_argument("--data", default="lm", choices=["lm", "uniform"])
     # --- async actor/learner runtime (repro.runtime) ---
     ap.add_argument("--runtime", default="sync", choices=["sync", "async"])
-    ap.add_argument("--transport", default="process",
-                    choices=["thread", "process"])
-    ap.add_argument("--compilation-cache", default=None,
-                    help="persistent jax compilation cache dir shipped to "
-                         "spawned workers (default: shared tempdir path "
-                         "for --transport process)")
+    ap.add_argument("--transport", default="thread",
+                    choices=["thread", "process"],
+                    help="process: one OS process per client, CPU only "
+                         "(a chip belongs to one process)")
     ap.add_argument("--clients", type=int, default=3)
     ap.add_argument("--rounds", type=int, default=2)
     ap.add_argument("--staleness-bound", type=int, default=0)
@@ -194,11 +192,23 @@ def main():
                          "(default: crashes are permanent)")
     ap.add_argument("--bench-out", default=None,
                     help="write the async run summary as JSON here")
-    args = ap.parse_args()
+    return ap
 
-    if args.runtime == "async":
-        return run_async(args)
 
+class SyncRun(NamedTuple):
+    """What ``run_sync`` leaves behind: the final state, every step's
+    loss, the jitted step and the last batch it was called with (so a
+    caller can ``step_fn.lower(state, batch, seed)`` the program that
+    ran)."""
+
+    state: Dict[str, Any]
+    losses: List[float]
+    step_fn: Any
+    batch: Dict[str, Any]
+
+
+def run_sync(args, log=print) -> SyncRun:
+    """The synchronous training loop behind ``main``."""
     cfg = (configs.get_smoke_config(args.arch) if args.smoke
            else configs.get_config(args.arch))
     if args.smoke:
@@ -228,8 +238,8 @@ def main():
             # been written on a different pod count)
             state, last = steps.restore_train_state(
                 args.checkpoint_dir, cfg, tc, mesh)
-            print(f"[train] resumed step {last} onto mesh "
-                  f"{dict(mesh.shape)}")
+            log(f"[train] resumed step {last} onto mesh "
+                f"{dict(mesh.shape)}")
 
     ckpt = None
     if args.checkpoint_dir:
@@ -237,26 +247,42 @@ def main():
             args.checkpoint_dir, keep_last_k=args.keep_last_k,
             mesh_axes=dict(mesh.shape))
 
-    step_fn = jax.jit(steps.build_train_step(cfg, tc, mesh))
+    # the state is donated: without it the old and new state (5.6 GB
+    # each for full-width qwen1.5-0.5b) are live together and the step
+    # no longer fits one v5e chip
+    step_fn = jax.jit(steps.build_train_step(cfg, tc, mesh),
+                      donate_argnums=0)
     dc = synthetic.DataConfig(vocab=cfg.vocab, seq_len=seq, global_batch=batch,
                               kind=args.data)
     batch_fn = synthetic.batch_fn(dc)
 
     first = int(state["step"])
+    losses: List[float] = []
+    data = None
     t0 = time.time()
     for i in range(first, first + args.steps):
         data = synthetic.with_frontend_stubs(batch_fn(dc, i), cfg)
         state, m = step_fn(state, data, jnp.int32(i))
+        losses.append(float(m["loss"]))
         if i % 10 == 0 or i == first + args.steps - 1:
             dt = time.time() - t0
-            print(f"[train] step {i:6d} loss {float(m['loss']):.4f} "
-                  f"({(i - first + 1) * batch * seq / max(dt, 1e-9):,.0f} tok/s)")
+            log(f"[train] step {i:6d} loss {losses[-1]:.4f} "
+                f"({(i - first + 1) * batch * seq / max(dt, 1e-9):,.0f} tok/s)")
         if ckpt is not None and (i + 1) % args.checkpoint_every == 0:
             ckpt.save(i + 1, state)
-            print(f"[train] checkpoint {i + 1} queued (async)")
+            log(f"[train] checkpoint {i + 1} queued (async)")
     if ckpt is not None:
         ckpt.close()
-    print("[train] done")
+    log("[train] done")
+    return SyncRun(state, losses, step_fn, data)
+
+
+def main():
+    args = build_parser().parse_args()
+    compile_cache.use_persistent_cache()
+    if args.runtime == "async":
+        return run_async(args)
+    run_sync(args)
 
 
 if __name__ == "__main__":

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import zlib
 from typing import Any, Callable, Dict, Optional, Tuple, Union
 
 import jax
@@ -64,12 +65,14 @@ def _map_specs(fn: Callable[[Tuple[str, ...], ParamSpec], Any], specs: PyTree):
 
 
 def init_params(specs: PyTree, key) -> PyTree:
-    """Materialize parameters; deterministic per-path keys."""
+    """Materialize parameters; deterministic per-path keys.  The path
+    names are folded in by CRC32: ``hash(str)`` changes with every
+    process, so the same seed would give other weights in each run."""
 
     def make(path, spec):
         k = key
         for p in path:
-            k = jax.random.fold_in(k, hash(p) & 0x7FFFFFFF)
+            k = jax.random.fold_in(k, zlib.crc32(str(p).encode()) & 0x7FFFFFFF)
         return _init_one(k, spec)
 
     return _map_specs(make, specs)

@@ -40,6 +40,7 @@ import numpy as np
 # repro.runtime.protocol, so this module may load while federated is
 # still mid-import — attributes are resolved at call time, never here.
 import repro.fl.federated as federated
+from repro import compile_cache
 from repro.runtime import protocol
 from repro.runtime.buffer import RoundBuffer, combine_weights, staleness_weight
 from repro.runtime.chaos import FaultPlan, LearnerKilled
@@ -73,8 +74,6 @@ class ClientSpec:
     heartbeat_interval_s: Optional[float] = None  # None = no beacons
     join_on_start: bool = False  # announce ourselves before the first round
     chaos: Optional[FaultPlan] = None
-    compilation_cache_dir: Optional[str] = None  # persistent jax
-    #   compilation cache for spawned workers (see _setup_compilation_cache)
 
 
 def _is_straggler(spec: ClientSpec, rnd: int) -> bool:
@@ -82,26 +81,6 @@ def _is_straggler(spec: ClientSpec, rnd: int) -> bool:
         return False
     rng = np.random.default_rng((spec.seed, spec.client_id, rnd))
     return bool(rng.random() < spec.straggler_fraction)
-
-
-def _setup_compilation_cache(cache_dir: str) -> None:
-    """Point this worker at a persistent on-disk jax compilation cache.
-    Every spawned client process traces the same workload jits from
-    scratch; a shared cache dir turns N identical compiles into one
-    compile plus N-1 disk loads, and survives across rounds and runs.
-    Best-effort: a worker must never die over a cache misconfig."""
-    import os
-
-    try:
-        import jax
-
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # cache tiny/fast client kernels too (defaults skip them)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception:  # noqa: BLE001
-        pass
 
 
 def _safe_send(endpoint: ClientEndpoint, msg) -> None:
@@ -158,8 +137,7 @@ class _HeartbeatBeacon:
 
 
 def run_client(endpoint: ClientEndpoint, spec: ClientSpec) -> None:
-    if spec.compilation_cache_dir:
-        _setup_compilation_cache(spec.compilation_cache_dir)
+    compile_cache.use_persistent_cache()
     grad = spec.workload.build()
     chaos = spec.chaos
     if spec.join_on_start:

@@ -14,8 +14,6 @@ jitted codec from `runtime.protocol`.
 from __future__ import annotations
 
 import dataclasses
-import os
-import tempfile
 from typing import List, Optional, Tuple
 
 import numpy as np
@@ -76,11 +74,6 @@ class RuntimeConfig:
     # transport
     transport: str = "thread"  # thread | process
     drop_prob: float = 0.0
-    # persistent jax compilation cache shipped to spawned workers; None
-    # auto-derives a shared dir under the system tempdir for the
-    # process transport (threads share the parent's in-memory jit cache
-    # already and get nothing from it)
-    compilation_cache_dir: Optional[str] = None
     # elastic membership: a member whose last heartbeat/update is older
     # than this is evicted (leaves future announced cohorts); clients
     # beacon at timeout/4.  None disables the protocol entirely.
@@ -153,10 +146,6 @@ class AsyncFederatedRuntime:
             bits_per_coord_analytic=analytic_bits_per_coord(
                 fl.mechanism, fl.n_clients, fl.sigma, fl.clip)
         )
-        cache_dir = cfg.compilation_cache_dir
-        if cache_dir is None and cfg.transport == "process":
-            cache_dir = os.path.join(tempfile.gettempdir(),
-                                     "repro-jax-cache")
         heartbeat_interval = (None if cfg.heartbeat_timeout_s is None
                               else cfg.heartbeat_timeout_s / 4.0)
         specs = [
@@ -168,7 +157,6 @@ class AsyncFederatedRuntime:
                 straggler_delay_s=cfg.straggler_delay_s,
                 heartbeat_interval_s=heartbeat_interval,
                 chaos=cfg.chaos,
-                compilation_cache_dir=cache_dir,
             )
             for i in range(fl.n_clients)
         ]

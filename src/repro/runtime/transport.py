@@ -7,7 +7,9 @@ Two implementations behind one endpoint API:
     the runtime benchmark.
   * ProcessTransport — `multiprocessing` (spawn) queues, clients as real
     OS processes each with their own jax runtime.  The CI smoke path
-    (`launch/train.py --runtime async --transport process`).
+    (`launch/train.py --runtime async --transport process`).  CPU only:
+    an accelerator belongs to the one process that touched it first,
+    so spawned clients could never reach the parent's chip.
 
 Both preserve integer payloads exactly (numpy arrays cross either
 boundary bit-for-bit; the runtime tests pin this).  Loss injection
@@ -180,6 +182,15 @@ class ProcessTransport(_BaseTransport):
         self._procs: List[Any] = []
 
     def start_clients(self, target: Callable, specs: Sequence[Any]) -> None:
+        import jax
+
+        backend = jax.default_backend()
+        if backend != "cpu":
+            raise RuntimeError(
+                f"ProcessTransport needs a CPU backend, but this process "
+                f"holds {backend!r}: a chip belongs to one process, so "
+                f"spawned clients cannot use it. Use the thread "
+                f"transport, or run with JAX_PLATFORMS=cpu.")
         for i, spec in enumerate(specs):
             p = self._ctx.Process(
                 target=target, args=(self.client_endpoint(i), spec),

@@ -59,7 +59,7 @@ def test_compress_tree_homomorphic_psum_matches_mean():
     """Across a real pod axis the homomorphic mechanisms return the
     cross-client mean up to the mechanism's noise scale."""
     n, d, sigma = 8, 4096, 1e-3
-    mesh = jax.make_mesh((8, 1, 1), ("pod", "data", "model"))
+    mesh = meshctx.make_mesh((8, 1, 1))
     xs = jax.random.uniform(jax.random.PRNGKey(0), (n, d), minval=-0.5, maxval=0.5)
     for mechanism in ["aggregate_gaussian", "aggregate_laplace",
                       "irwin_hall", "layered_shifted"]:
@@ -106,7 +106,7 @@ def test_message_bits_none_is_float32():
 
 # ------------------------------------------------------------- sharding
 def _mesh222():
-    return jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    return meshctx.make_mesh((2, 2, 2))
 
 
 def test_param_rules_dense_vs_ep_moe():

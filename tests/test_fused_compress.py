@@ -14,11 +14,12 @@ from repro.core import dither
 from repro.core.irwin_hall import NormalizedIrwinHall
 from repro.core.packing import geometry_for_bits, geometry_for_range
 from repro.dist import compress as dc
+from repro.dist import meshctx
 from repro.kernels import ops, ref
 from repro.runtime import protocol
 
 # bits=4 fields hold at most n=2 summed messages with m_max >= 2
-N_FOR_BITS = {4: 2, 8: 4, 16: 4}
+N_FOR_BITS = {4: 2, 8: 4, 16: 4, 24: 4}
 SIGMA = 0.02
 
 
@@ -88,17 +89,16 @@ def test_fused_pallas_matches_xla_words(mechanism, bits):
     w_x = ops.fused_pack_encode(xs[0], ss[0], step, geom.bits, geom.m_max,
                                 impl="xla")
     assert bool(jnp.all(w_p == w_x))
-    s_eff = ss[0] + float(geom.bias)
-    y_p = ops.fused_unpack_decode(w_p, s_eff, step, offset, geom.bits,
-                                  shape, impl="pallas")
-    y_x = ops.fused_unpack_decode(w_x, s_eff, step, offset, geom.bits,
-                                  shape, impl="xla")
+    y_p = ops.fused_unpack_decode(w_p, ss[0], geom.bias, step, offset,
+                                  geom.bits, shape, impl="pallas")
+    y_x = ops.fused_unpack_decode(w_x, ss[0], geom.bias, step, offset,
+                                  geom.bits, shape, impl="xla")
     np.testing.assert_allclose(np.asarray(y_p), np.asarray(y_x), atol=1e-6)
 
 
 # ------------------------------------------------- aggregated decode
 @pytest.mark.parametrize("mechanism", dc.HOMOMORPHIC)
-@pytest.mark.parametrize("bits", [4, 8, 16])
+@pytest.mark.parametrize("bits", [4, 8, 16, 24])
 def test_fused_sum_decode_matches_unfused(mechanism, bits):
     """Summed packed words decode to the unfused sum decode (float ulp)."""
     shape = (8192,)
@@ -122,6 +122,9 @@ def test_fused_sum_decode_matches_unfused(mechanism, bits):
 # ------------------------------------------------- exact law after fusion
 @pytest.mark.parametrize("mechanism,bits,sigma", [
     ("aggregate_gaussian", 16, 0.1),
+    # the default int32 width: biased field sums near 2^24, where a
+    # dither folded into the f32 bias would round to an integer
+    ("aggregate_gaussian", 24, 0.1),
     ("aggregate_laplace", 16, 0.1),
     ("irwin_hall", 8, 5e-3),
 ])
@@ -186,7 +189,7 @@ def test_compress_tree_fused_psum_matches_unfused():
     """Across a real 8-pod mesh the fused packed psum reproduces the
     unfused collective's output and noise scale."""
     n, d, sigma = 8, 4096, 1e-3
-    mesh = jax.make_mesh((8, 1, 1), ("pod", "data", "model"))
+    mesh = meshctx.make_mesh((8, 1, 1))
     xs = jax.random.uniform(jax.random.PRNGKey(0), (n, d),
                             minval=-0.5, maxval=0.5)
     for mechanism in dc.HOMOMORPHIC:

@@ -123,7 +123,7 @@ from repro.dist.compress import CompressionConfig
 from repro.data import synthetic
 from repro.train import steps
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = meshctx.make_mesh((2, 2, 2))
 meshctx.set_mesh(mesh)
 cfg = configs.get_smoke_config("qwen3-32b").scaled(compute_dtype="float32")
 comp = CompressionConfig(mechanism="aggregate_gaussian", sigma=5e-4, clip=0.5,
@@ -158,8 +158,9 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys; sys.path.insert(0, "src")
 import jax
+from repro.dist import meshctx
 from repro.launch import dryrun
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = meshctx.make_mesh((2, 2, 2))
 fn, args, sh = dryrun.build_cell("qwen1.5-0.5b", "decode_32k", mesh)
 compiled = jax.jit(fn, in_shardings=sh).lower(*args).compile()
 mem = compiled.memory_analysis()
@@ -186,9 +187,10 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys, math; sys.path.insert(0, "src")
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
+from repro.dist import meshctx
 from repro.dist.compress import CompressionConfig, compress_tree
 
-mesh = jax.make_mesh((8,), ("pod",))
+mesh = meshctx.make_mesh((8,), ("pod",))
 n, d, sigma = 8, 40_000, 0.25
 cfg = CompressionConfig(mechanism="aggregate_gaussian", sigma=sigma, clip=4.0,
                         msg_dtype="int32")
@@ -235,7 +237,7 @@ from repro.dist import meshctx
 from repro.models import moe, nn
 cfg = configs.get_smoke_config("dbrx-132b").scaled(compute_dtype="float32")
 for mesh_shape in [(1, 4), (2, 2)]:
-    mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+    mesh = meshctx.make_mesh(mesh_shape, ("data", "model"))
     meshctx.set_mesh(mesh)
     params = {"moe": nn.init_params(moe.moe_specs(cfg), jax.random.PRNGKey(0))}
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model)) * 0.5

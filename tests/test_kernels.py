@@ -62,10 +62,10 @@ def test_fused_agg_kernel_vs_oracle(bits, m_max, percoord):
     assert w_p.dtype == jnp.int32
     assert bool(jnp.all(w_p == w_x))
     offset = None if percoord else 0.125
-    s_eff = s + float(m_max)  # one message summed: r = 1
-    y_p = ops.fused_unpack_decode(w_p, s_eff, step, offset, bits, shape,
+    # one message summed: r = 1, packing bias m_max
+    y_p = ops.fused_unpack_decode(w_p, s, m_max, step, offset, bits, shape,
                                   impl="pallas")
-    y_x = ops.fused_unpack_decode(w_x, s_eff, step, offset, bits, shape,
+    y_x = ops.fused_unpack_decode(w_x, s, m_max, step, offset, bits, shape,
                                   impl="xla")
     np.testing.assert_allclose(np.asarray(y_p), np.asarray(y_x), atol=1e-6)
     m = jnp.clip(jnp.floor(x / step + s + 0.5), -m_max, m_max)

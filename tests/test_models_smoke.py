@@ -116,3 +116,24 @@ def test_mamba2_chunked_equals_stepwise():
         jnp.max(jnp.abs(y_chunk - y_step))
     )
     assert jnp.allclose(h_final, state, atol=2e-3)
+
+
+def test_init_params_same_in_every_process():
+    """One seed gives the same weights in every process, whatever
+    Python's per-process string-hash salt."""
+    import os
+    import subprocess
+    import sys
+
+    code = ("import jax, numpy as np; from repro.models import nn; "
+            "s = {'w': nn.ParamSpec((4, 8), (None, None)), "
+            "'e': {'b': nn.ParamSpec((8,), (None,))}}; "
+            "p = nn.init_params(s, jax.random.PRNGKey(0)); "
+            "print(repr([np.asarray(x).tolist() for x in jax.tree.leaves(p)]))")
+    outs = []
+    for salt in ("1", "2"):
+        env = dict(os.environ, PYTHONHASHSEED=salt, JAX_PLATFORMS="cpu")
+        outs.append(subprocess.run(
+            [sys.executable, "-c", code], env=env, check=True,
+            capture_output=True, text=True).stdout)
+    assert outs[0] == outs[1]

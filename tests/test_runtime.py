@@ -137,6 +137,47 @@ def test_transport_integer_roundtrip_exact(kind):
         transport.shutdown()
 
 
+def test_process_transport_refuses_accelerator_parent(monkeypatch):
+    """A chip belongs to the process that touched it first: with a
+    non-CPU backend in the parent, spawning clients fails loudly
+    instead of leaving children that cannot reach the device."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    transport = make_transport("process", 1)
+    spec = ClientSpec(client_id=0, seed=SEED,
+                      proto=RoundProtocol(mechanism="aggregate_gaussian",
+                                          sigma=1e-2, clip=1.0),
+                      workload=QuadraticWorkload(1, D, seed=SEED))
+    try:
+        with pytest.raises(RuntimeError, match="needs a CPU backend"):
+            transport.start_clients(run_client, [spec])
+        assert transport._procs == []
+    finally:
+        transport.shutdown()
+
+
+def test_compile_cache_honours_env(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code;
+    without it the cache sits at the checkout's fixed .jax_cache."""
+    import pathlib
+
+    import jax
+
+    from repro import compile_cache
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a: updates.append(a))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.use_persistent_cache() == str(tmp_path)
+    assert updates == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    default = pathlib.Path(__file__).resolve().parents[1] / ".jax_cache"
+    assert compile_cache.use_persistent_cache() == str(default)
+    assert updates == [("jax_compilation_cache_dir", str(default))]
+
+
 def test_client_endpoint_drop_injection_and_retry():
     """Injected loss raises TransportError; the actor's bounded retry
     eventually lands every update (deterministic drop rng)."""
