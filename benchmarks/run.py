@@ -1,11 +1,11 @@
-"""Benchmark harness — one module per paper table/figure + roofline.
+"""Benchmark harness — one module per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV rows.  ``us_per_call`` is the
 benchmark's primary value (bits, MSE, entropy, seconds — stated in the
 ``derived`` column); each module's docstring maps it to the paper
 artifact it reproduces (see DESIGN.md §6).
 
-    PYTHONPATH=src python -m benchmarks.run [--only fig2,roofline]
+    PYTHONPATH=src python -m benchmarks.run [--only fig2,fig4]
 """
 from __future__ import annotations
 
@@ -31,7 +31,6 @@ MODULES = [
     "bench_runtime",
     "bench_compress",
     "bench_serve",
-    "roofline",
 ]
 
 

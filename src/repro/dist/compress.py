@@ -278,7 +278,8 @@ def decode_leaf_sum(m_sum, comp: CompressionConfig, n, r_msgs,
 def _compress_leaf(x, comp: CompressionConfig, key, axis: Optional[str],
                    n: int):
     dtype = x.dtype
-    x32 = jnp.clip(x.astype(jnp.float32), -comp.clip, comp.clip)
+    with jax.named_scope("encode"):
+        x32 = jnp.clip(x.astype(jnp.float32), -comp.clip, comp.clip)
     shape = x32.shape
 
     if comp.mechanism == "none_":
@@ -289,16 +290,24 @@ def _compress_leaf(x, comp: CompressionConfig, key, axis: Optional[str],
     idx = _client_index(axis)
 
     if comp.mechanism in HOMOMORPHIC:
-        step, offset, geom = _leaf_params(comp, n, kt, shape)
-        s_i = dither.dither_noise(jax.random.fold_in(ks, idx), shape)
-        m_sum = _psum_msg(encode_leaf(x32, comp, step, s_i, geom), comp, axis)
+        # fl.codec/<part> scopes name each part's device time in a trace
+        with jax.named_scope("draw"):
+            step, offset, geom = _leaf_params(comp, n, kt, shape)
+        with jax.named_scope("dither"):
+            s_i = dither.dither_noise(jax.random.fold_in(ks, idx), shape)
+        with jax.named_scope("encode"):
+            m = encode_leaf(x32, comp, step, s_i, geom)
+        with jax.named_scope("psum"):
+            m_sum = _psum_msg(m, comp, axis)
         if axis is not None:
-            s_sum, r_msgs = _dither_sum(ks, n, shape), n
+            with jax.named_scope("dither"):
+                s_sum, r_msgs = _dither_sum(ks, n, shape), n
         else:
             s_sum, r_msgs = s_i, 1
-        y = decode_leaf_sum(m_sum, comp, n, r_msgs, step, offset, s_sum,
-                            geom, shape)
-        return y.astype(dtype)
+        with jax.named_scope("decode"):
+            y = decode_leaf_sum(m_sum, comp, n, r_msgs, step, offset, s_sum,
+                                geom, shape)
+            return y.astype(dtype)
 
     if comp.mechanism in ("layered_shifted", "layered_direct"):
         # point-to-point AINQ per client (per-client noise N(0, n s^2)
@@ -328,10 +337,11 @@ def compress_tree(grads: PyTree, comp: CompressionConfig, key,
     """
     n = max(int(n_clients), 1)
     leaves, treedef = jax.tree.flatten(grads)
-    out = [
-        _compress_leaf(g, comp, jax.random.fold_in(key, i), axis, n)
-        for i, g in enumerate(leaves)
-    ]
+    with jax.named_scope("fl.codec"):
+        out = [
+            _compress_leaf(g, comp, jax.random.fold_in(key, i), axis, n)
+            for i, g in enumerate(leaves)
+        ]
     return jax.tree.unflatten(treedef, out)
 
 

@@ -7,7 +7,7 @@ os.environ["XLA_FLAGS"] = (
 
 """Multi-pod dry-run: lower + compile every (architecture x input shape)
 cell on the production meshes and record memory / cost / collective
-statistics for the roofline analysis (EXPERIMENTS.md).
+statistics.
 
 No arrays are allocated: all inputs are ShapeDtypeStructs; the compiled
 executable is inspected, never executed.

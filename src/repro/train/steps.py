@@ -170,7 +170,10 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh: Mesh):
         # style gather-once needs manual double-buffered scheduling that
         # GSPMD cannot express; kept per-microbatch here.
         def mb_loss(p, mb):
-            return loss_fn(_compute_copy(p), mb)
+            # under value_and_grad the backward is traced as
+            # transpose(jvp(fl.forward)), the remat recompute inside it
+            with jax.named_scope("fl.forward"):
+                return loss_fn(_compute_copy(p), mb)
 
         if tc.grad_accum <= 1:
             return jax.value_and_grad(mb_loss)(params, batch)
@@ -187,8 +190,10 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh: Mesh):
         return l * inv, jax.tree.map(lambda x: x * inv, g)
 
     def apply_update(state, grads, loss, cohort):
-        updates, opt_state = opt.update(grads, state["opt_state"], state["params"])
-        params = jax.tree.map(jnp.add, state["params"], updates)
+        with jax.named_scope("fl.optimizer"):
+            updates, opt_state = opt.update(grads, state["opt_state"],
+                                            state["params"])
+            params = jax.tree.map(jnp.add, state["params"], updates)
         return (
             {"params": params, "opt_state": opt_state, "step": state["step"] + 1},
             {"loss": loss, "cohort": cohort},
