@@ -94,7 +94,7 @@ def phase_train(ctx):
 
     from repro.launch import train
 
-    # two steps: on a v5e each spends about four minutes drawing the
+    # two steps: on a v5e each spends about 9 s drawing the
     # per-coordinate DECOMPOSE (A, B) for the model's 464M coordinates
     argv = ["--arch", ARCH, "--mechanism", "aggregate_gaussian", "--fused",
             "--steps", str(TRAIN_STEPS)]

@@ -1,11 +1,13 @@
-"""Compile the fused codec kernels for a described TPU v5e, without one.
+"""Compile the codec's device programs for a described TPU v5e, without one.
 
 The Pallas interpreter runs the kernels everywhere else in the suite;
 only the chip's own compiler refuses a tile that is not aligned, a
 kernel that wants more VMEM than it may use, or a program that does not
 fit HBM.  These tests lower the fused encode and decode at the largest
 leaf of qwen1.5-0.5b (its 151936 x 1024 embedding) for a ``v5e:2x2``
-topology and check that the compiled program holds the kernel.
+topology and check that the compiled program holds the kernel.  The
+per-coordinate DECOMPOSE draw is compiled at one full batch of lanes, to
+hold its lookups' knot comparisons fused into their counts.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU compiler's library.
@@ -14,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.core.aggregate import DECOMPOSE_BATCH, AggregateGaussianMechanism
 from repro.core.packing import geometry_for_bits
 from repro.kernels import ops
 
@@ -76,3 +79,16 @@ def test_fused_codec_compiles_for_v5e(one_chip, direction, bits, percoord):
         args = (word, leaf, bias) + ((leaf, leaf) if percoord else ())
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("n", [1, 4])
+def test_percoord_draw_compiles_for_v5e(one_chip, n):
+    """One DECOMPOSE_BATCH of per-coordinate (A, B).  Each lookup's knot
+    comparisons stay fused into its counts, and the temporaries (a
+    gathered 128-knot row is 512 MiB at 2^20 lanes) stay under 2 GiB."""
+    mech = AggregateGaussianMechanism(n, 0.05, per_coord=True)
+    key = _sds((2,), jnp.uint32, one_chip)
+    compiled = jax.jit(
+        lambda k: mech.global_randomness(k, (DECOMPOSE_BATCH,))
+    ).lower(key).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
